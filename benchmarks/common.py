@@ -1,5 +1,4 @@
-"""Shared benchmark utilities: timing, CSV emission, smoke mode, JSON
-artifacts.
+"""Shared benchmark utilities: CSV emission, smoke mode, JSON artifacts.
 
 Smoke mode (``run.py --smoke``) is the CI-sized variant: every module
 shrinks its shapes/steps/grids so the whole suite finishes in minutes on
@@ -11,10 +10,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
-from typing import Callable
-
-import jax
 
 SMOKE = False
 OUT_DIR = "."
@@ -39,23 +34,6 @@ def set_out_dir(path: str) -> None:
     global OUT_DIR
     OUT_DIR = path
     os.makedirs(path, exist_ok=True)
-
-
-def time_jit(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> float:
-    """Median wall-time (us) of a jitted callable on this host."""
-    if SMOKE:
-        warmup, iters = 1, 2
-    for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out)
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6
 
 
 def emit(name: str, us_per_call: float, derived: str):

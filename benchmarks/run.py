@@ -10,9 +10,6 @@ gates regressions.
   bench_memory      -- Table 2 (activation memory), Fig. 6 (max batch)
   bench_convergence -- Table 1 (accuracy), Fig. 7 (budget), Fig. 8
                        (estimator ablation), fixed-vs-adaptive budgets
-  bench_latency     -- Table 3 (linear fwd/bwd latency)
-  bench_kernels     -- fused sampled-dW kernel vs unfused composition
-                       (gated by check_kernel_baseline.py in CI)
   bench_roofline    -- roofline terms per (arch x shape x mesh) cell
   bench_serving     -- continuous batching vs sequential: requests/s,
                        p50/p99 latency under a Poisson open-loop trace
@@ -30,8 +27,7 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 MODULES = ["bench_estimators", "bench_memory", "bench_convergence",
-           "bench_latency", "bench_kernels", "bench_roofline",
-           "bench_serving"]
+           "bench_roofline", "bench_serving"]
 
 
 def main() -> None:

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import optim as optim_lib
+from repro import spans
 from repro.api.spec import RunSpec
 from repro.configs import get_config
 from repro.launch import mesh as mesh_lib
@@ -143,7 +144,8 @@ class Run:
         ``sample_ids`` entry is consumed by the znorm cache and dropped
         automatically when the policy needs none)."""
         self.init()
-        b = {k: jnp.asarray(v) for k, v in batch.items()}
+        with spans.host_span(spans.STEP_PUT):
+            b = {k: jnp.asarray(v) for k, v in batch.items()}
         if not self.use_znorm_cache:
             b.pop("sample_ids", None)
         elif "sample_ids" not in b:
@@ -154,10 +156,12 @@ class Run:
         s = int(self.state["step"])
         # the ambient mesh lets the Pallas kernels run per data shard
         # (kernels.ops); the compiler cannot partition them itself
-        with (jax.set_mesh(self.mesh) if self.mesh is not None
-              else contextlib.nullcontext()):
+        mesh = (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+        with mesh, spans.host_span(spans.STEP_DISPATCH):
             self.state, metrics = self.step_fn(self.state, b)
-        m = {k: float(v) for k, v in metrics.items()}
+        with spans.host_span(spans.STEP_FETCH):
+            m = {k: float(v) for k, v in metrics.items()}
         self.history.append({"step": s, **m})
         return m
 

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from repro import spans
 from repro.core import estimator_registry as registry
 from repro.core import plans
 from repro.core.config import WTACRSConfig
@@ -96,18 +97,19 @@ def _sampled_dw(h_sub, dz, idx, scale, cfg: WTACRSConfig, out_dtype):
     and scale the k rows of dZ the same way (``gather_scaled``); the
     contraction is the Pallas kernel when ``cfg.kernel`` routes to
     Pallas (blocks from the autotuner's tuning table), else one batched
-    dot_general."""
-    if cfg.kernel.use_pallas:
-        from repro.kernels import ops as kernel_ops
-        dw = kernel_ops.fused_sampled_dw(h_sub, dz, idx, scale,
-                                         kernel=cfg.kernel)
-    else:
-        from repro.kernels.fused_sampling import gather_scaled
-        dz_sub = gather_scaled(dz, idx, scale)                 # (B, k, E)
-        dw = jax.lax.dot_general(
-            h_sub, dz_sub, (((0, 1), (0, 1)), ((), ())),
-            preferred_element_type=jnp.float32)
-    return dw.astype(out_dtype)
+    dot_general.  Both run under the ``wtacrs_dw`` scope."""
+    with jax.named_scope(spans.WTACRS_DW):
+        if cfg.kernel.use_pallas:
+            from repro.kernels import ops as kernel_ops
+            dw = kernel_ops.fused_sampled_dw(h_sub, dz, idx, scale,
+                                             kernel=cfg.kernel)
+        else:
+            from repro.kernels.fused_sampling import gather_scaled
+            dz_sub = gather_scaled(dz, idx, scale)             # (B, k, E)
+            dw = jax.lax.dot_general(
+                h_sub, dz_sub, (((0, 1), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return dw.astype(out_dtype)
 
 
 def _sq_norm_tap(dz):
@@ -120,8 +122,9 @@ def _sq_norm_tap(dz):
 def _sampled_linear_fwd(h, w, key_data, znorm, cfg: WTACRSConfig):
     z = jnp.einsum("bsd,de->bse", h, w)
     k = cfg.budget_rows(h.shape[1])
-    idx, scale = _make_plans(h, znorm, key_data, cfg, k)
-    h_sub = _rowgather(h, idx)                                # (B, k, D)
+    with jax.named_scope(spans.WTACRS_PLAN):
+        idx, scale = _make_plans(h, znorm, key_data, cfg, k)
+        h_sub = _rowgather(h, idx)                            # (B, k, D)
     # Name the kept tensors so remat policies can save exactly these.
     h_sub = checkpoint_name(h_sub, "wtacrs_saved")
     idx = checkpoint_name(idx, "wtacrs_saved")
@@ -159,8 +162,9 @@ def _sampled_linear_shared(h, ws, key_data, znorm, cfg: WTACRSConfig):
 def _sampled_linear_shared_fwd(h, ws, key_data, znorm, cfg: WTACRSConfig):
     zs = tuple(jnp.einsum("bsd,de->bse", h, w) for w in ws)
     k = cfg.budget_rows(h.shape[1])
-    idx, scale = _make_plans(h, znorm, key_data, cfg, k)
-    h_sub = _rowgather(h, idx)
+    with jax.named_scope(spans.WTACRS_PLAN):
+        idx, scale = _make_plans(h, znorm, key_data, cfg, k)
+        h_sub = _rowgather(h, idx)
     h_sub = checkpoint_name(h_sub, "wtacrs_saved")
     idx = checkpoint_name(idx, "wtacrs_saved")
     scale = checkpoint_name(scale, "wtacrs_saved")

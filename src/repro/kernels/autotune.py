@@ -42,8 +42,7 @@ TABLE_VERSION = 1
 PACKAGED_TABLE = os.path.join(os.path.dirname(__file__),
                               "tuning_table.json")
 
-# Shapes the nightly refresh sweeps: (d_in, d_out, B, k, dtype).  The
-# first row is the bench_kernels default measurement shape.
+# Shapes the nightly refresh sweeps: (d_in, d_out, B, k, dtype).
 DEFAULT_SWEEP: Tuple[Tuple[int, int, int, int, str], ...] = (
     (256, 256, 8, 77, "float32"),
     (256, 256, 8, 77, "bfloat16"),

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro import optim as optim_lib
+from repro import spans
 from repro.optim import update as optim_state_update
 from repro.core import controller as controller_lib
 from repro.launch import mesh as mesh_lib
@@ -154,8 +155,9 @@ def make_train_step(cfg: ArchConfig, policy: cm.Policy,
                          and bool(opt_cfg.controller_rule_indices()))
 
     def loss_with_znorms(params, znorms, batch, key):
-        return registry.loss_fn(cfg, params, batch, policy, key=key,
-                                znorms=znorms)
+        with jax.named_scope(spans.FORWARD):
+            return registry.loss_fn(cfg, params, batch, policy, key=key,
+                                    znorms=znorms)
 
     def grads_of(params, znorms, batch, key):
         if use_znorm_cache:
@@ -261,13 +263,14 @@ def make_train_step(cfg: ArchConfig, policy: cm.Policy,
             aux, gz = {}, None
 
         lr = schedule(step)
-        if isinstance(opt_cfg, optim_lib.OptimSpec):
-            new_params, new_opt, om, rank_energy = optim_state_update(
-                gp, state["opt"], params, lr, opt_cfg)
-        else:
-            new_params, new_opt, om = optim.adamw_update(
-                gp, state["opt"], params, lr, opt_cfg)
-            rank_energy = {}
+        with jax.named_scope(spans.OPTIMIZER):
+            if isinstance(opt_cfg, optim_lib.OptimSpec):
+                new_params, new_opt, om, rank_energy = optim_state_update(
+                    gp, state["opt"], params, lr, opt_cfg)
+            else:
+                new_params, new_opt, om = optim.adamw_update(
+                    gp, state["opt"], params, lr, opt_cfg)
+                rank_energy = {}
         new_state = dict(state, params=new_params, opt=new_opt,
                          step=step + 1)
         if use_znorm_cache and microbatches > 1:

@@ -18,6 +18,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import spans
+
 NEG_INF = -1e30
 
 
@@ -39,105 +41,106 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     ``q_offset``: absolute position of q[0] (for chunked prefill).
     """
-    b, sq, h, dh = q.shape
-    skv, kvh = k.shape[1], k.shape[2]
-    g = h // kvh
-    q_block = min(q_block, sq)
-    kv_block = min(kv_block, skv)
-    assert sq % q_block == 0 and skv % kv_block == 0
-    nq, nk = sq // q_block, skv // kv_block
-    scale = 1.0 / math.sqrt(dh)
+    with jax.named_scope(spans.ATTENTION):
+        b, sq, h, dh = q.shape
+        skv, kvh = k.shape[1], k.shape[2]
+        g = h // kvh
+        q_block = min(q_block, sq)
+        kv_block = min(kv_block, skv)
+        assert sq % q_block == 0 and skv % kv_block == 0
+        nq, nk = sq // q_block, skv // kv_block
+        scale = 1.0 / math.sqrt(dh)
 
-    qr = q.reshape(b, nq, q_block, kvh, g, dh)
-    qr = jnp.moveaxis(qr, 1, 0)                     # (nq, B, bq, KVH, G, Dh)
-    kr = jnp.moveaxis(k.reshape(b, nk, kv_block, kvh, dh), 1, 0)
-    vr = jnp.moveaxis(v.reshape(b, nk, kv_block, kvh, dh), 1, 0)
+        qr = q.reshape(b, nq, q_block, kvh, g, dh)
+        qr = jnp.moveaxis(qr, 1, 0)                 # (nq, B, bq, KVH, G, Dh)
+        kr = jnp.moveaxis(k.reshape(b, nk, kv_block, kvh, dh), 1, 0)
+        vr = jnp.moveaxis(v.reshape(b, nk, kv_block, kvh, dh), 1, 0)
 
-    q_pos_base = jnp.arange(q_block) + q_offset
-    k_pos_base = jnp.arange(kv_block)
+        q_pos_base = jnp.arange(q_block) + q_offset
+        k_pos_base = jnp.arange(kv_block)
 
-    # Flash memory profile under AD: rematerialize the block probability
-    # matrices in the backward pass (this is what makes it "flash" — an
-    # un-rematted scan would store every (bq, bk) p-block, O(S^2) again).
-    @jax.checkpoint
-    def attend_block(qc, kc, vc, qi, kj, m, l, acc):
-        # qc: (B,bq,KVH,G,Dh) kc/vc: (B,bk,KVH,Dh)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qc.astype(jnp.float32),
-                       kc.astype(jnp.float32)) * scale
-        if causal:
-            qpos = q_pos_base + qi * q_block
-            kpos = k_pos_base + kj * kv_block
-            mask = qpos[:, None] >= kpos[None, :]
-            s = jnp.where(mask[None, None, None], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        # probabilities ride in the input dtype (bf16 on the TPU path):
-        # halves the dominant p-block HBM traffic; softmax stats stay f32
-        pv = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(qc.dtype),
-                        vc).astype(jnp.float32)
-        acc_new = acc * corr[..., None] + pv
-        return m_new, l_new, acc_new
-
-    def init_state():
-        m = jnp.full((b, kvh, g, q_block), NEG_INF, jnp.float32)
-        l = jnp.zeros((b, kvh, g, q_block), jnp.float32)
-        acc = jnp.zeros((b, kvh, g, q_block, dh), jnp.float32)
-        return m, l, acc
-
-    if mode == "triangular":
-        pairs = _block_pairs(nq, nk, causal)
-        qi_arr = jnp.array([p[0] for p in pairs], jnp.int32)
-        kj_arr = jnp.array([p[1] for p in pairs], jnp.int32)
-        last_arr = jnp.array([p[2] for p in pairs], jnp.bool_)
-
-        out0 = jnp.zeros((nq, b, kvh, g, q_block, dh), jnp.float32)
-
-        def step(carry, xs):
-            m, l, acc, out = carry
-            qi, kj, is_last = xs
-            qc = qr[qi]
-            kc, vc = kr[kj], vr[kj]
-            m, l, acc = attend_block(qc, kc, vc, qi, kj, m, l, acc)
-            o = acc / jnp.maximum(l, 1e-30)[..., None]
-            out = jax.lax.cond(
-                is_last, lambda o_: jax.lax.dynamic_update_slice(
-                    out, o[None], (qi, 0, 0, 0, 0, 0)),
-                lambda o_: out, o)
-            m0, l0, acc0 = init_state()
-            m = jnp.where(is_last, m0, m)
-            l = jnp.where(is_last, l0, l)
-            acc = jnp.where(is_last, acc0, acc)
-            return (m, l, acc, out), None
-
-        (m, l, acc, out), _ = jax.lax.scan(
-            step, init_state() + (out0,), (qi_arr, kj_arr, last_arr))
-        o = out                                          # (nq,B,KVH,G,bq,Dh)
-    else:
+        # Flash memory profile under AD: rematerialize the block probability
+        # matrices in the backward pass (this is what makes it "flash" — an
+        # un-rematted scan would store every (bq, bk) p-block, O(S^2) again).
         @jax.checkpoint
-        def q_row(qc, qi):
-            def kv_step(carry, kblk):
-                kc, vc, kj = kblk
-                m, l, acc = carry
-                return attend_block(qc, kc, vc, qi, kj, m, l, acc), None
+        def attend_block(qc, kc, vc, qi, kj, m, l, acc):
+            # qc: (B,bq,KVH,G,Dh) kc/vc: (B,bk,KVH,Dh)
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", qc.astype(jnp.float32),
+                           kc.astype(jnp.float32)) * scale
+            if causal:
+                qpos = q_pos_base + qi * q_block
+                kpos = k_pos_base + kj * kv_block
+                mask = qpos[:, None] >= kpos[None, :]
+                s = jnp.where(mask[None, None, None], s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + jnp.sum(p, axis=-1)
+            # probabilities ride in the input dtype (bf16 on the TPU path):
+            # halves the dominant p-block HBM traffic; softmax stats stay f32
+            pv = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(qc.dtype),
+                            vc).astype(jnp.float32)
+            acc_new = acc * corr[..., None] + pv
+            return m_new, l_new, acc_new
 
-            (m, l, acc), _ = jax.lax.scan(
-                kv_step, init_state(),
-                (kr, vr, jnp.arange(nk, dtype=jnp.int32)))
-            return acc / jnp.maximum(l, 1e-30)[..., None]
+        def init_state():
+            m = jnp.full((b, kvh, g, q_block), NEG_INF, jnp.float32)
+            l = jnp.zeros((b, kvh, g, q_block), jnp.float32)
+            acc = jnp.zeros((b, kvh, g, q_block, dh), jnp.float32)
+            return m, l, acc
 
-        def q_step(_, qblk):
-            qc, qi = qblk
-            return None, q_row(qc, qi)
+        if mode == "triangular":
+            pairs = _block_pairs(nq, nk, causal)
+            qi_arr = jnp.array([p[0] for p in pairs], jnp.int32)
+            kj_arr = jnp.array([p[1] for p in pairs], jnp.int32)
+            last_arr = jnp.array([p[2] for p in pairs], jnp.bool_)
 
-        _, o = jax.lax.scan(q_step, None,
-                            (qr, jnp.arange(nq, dtype=jnp.int32)))
+            out0 = jnp.zeros((nq, b, kvh, g, q_block, dh), jnp.float32)
 
-    # (nq, B, KVH, G, bq, Dh) -> (B, Sq, H, Dh)
-    o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 4, 2, 3, 5)
-    o = o.reshape(b, sq, h, dh)
-    return o.astype(q.dtype)
+            def step(carry, xs):
+                m, l, acc, out = carry
+                qi, kj, is_last = xs
+                qc = qr[qi]
+                kc, vc = kr[kj], vr[kj]
+                m, l, acc = attend_block(qc, kc, vc, qi, kj, m, l, acc)
+                o = acc / jnp.maximum(l, 1e-30)[..., None]
+                out = jax.lax.cond(
+                    is_last, lambda o_: jax.lax.dynamic_update_slice(
+                        out, o[None], (qi, 0, 0, 0, 0, 0)),
+                    lambda o_: out, o)
+                m0, l0, acc0 = init_state()
+                m = jnp.where(is_last, m0, m)
+                l = jnp.where(is_last, l0, l)
+                acc = jnp.where(is_last, acc0, acc)
+                return (m, l, acc, out), None
+
+            (m, l, acc, out), _ = jax.lax.scan(
+                step, init_state() + (out0,), (qi_arr, kj_arr, last_arr))
+            o = out                                      # (nq,B,KVH,G,bq,Dh)
+        else:
+            @jax.checkpoint
+            def q_row(qc, qi):
+                def kv_step(carry, kblk):
+                    kc, vc, kj = kblk
+                    m, l, acc = carry
+                    return attend_block(qc, kc, vc, qi, kj, m, l, acc), None
+
+                (m, l, acc), _ = jax.lax.scan(
+                    kv_step, init_state(),
+                    (kr, vr, jnp.arange(nk, dtype=jnp.int32)))
+                return acc / jnp.maximum(l, 1e-30)[..., None]
+
+            def q_step(_, qblk):
+                qc, qi = qblk
+                return None, q_row(qc, qi)
+
+            _, o = jax.lax.scan(q_step, None,
+                                (qr, jnp.arange(nq, dtype=jnp.int32)))
+
+        # (nq, B, KVH, G, bq, Dh) -> (B, Sq, H, Dh)
+        o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 4, 2, 3, 5)
+        o = o.reshape(b, sq, h, dh)
+        return o.astype(q.dtype)
 
 
 def attention_reference(q, k, v, *, causal=True, q_offset: int = 0):

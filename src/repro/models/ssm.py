@@ -21,6 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.models import common as cm
 
 
@@ -266,10 +267,12 @@ def apply_mlstm(cfg, p, ctx: cm.Ctx, h, chunk: int = 256,
     to_seq = lambda x: jnp.moveaxis(x.astype(jnp.float32), 1, 0)
     state = mlstm_decode_init(cfg, bsz)
     state = (state["c"], state["n"], state["m"])
-    (cs, ns, ms), hs = _recurrent_over_chunks(
-        _mlstm_cell_step, state,
-        (to_seq(q), to_seq(k), to_seq(v), to_seq(i_raw), to_seq(f_raw)),
-        chunk)
+    with jax.named_scope(spans.MLSTM_SCAN):
+        (cs, ns, ms), hs = _recurrent_over_chunks(
+            _mlstm_cell_step, state,
+            (to_seq(q), to_seq(k), to_seq(v), to_seq(i_raw),
+             to_seq(f_raw)),
+            chunk)
     hs = jnp.moveaxis(hs, 0, 1).reshape(bsz, l, di)     # (B,L,di)
     y = hs.astype(h.dtype) * jax.nn.silu(z)
     out = ctx.linear("mlstm_down", y, p["down"])
@@ -362,7 +365,8 @@ def apply_slstm(cfg, p, ctx: cm.Ctx, h, chunk: int = 256,
     state = slstm_decode_init(cfg, bsz)
     state = (state["c"], state["n"], state["m"], state["h"])
     step = _slstm_cell_step_factory(p, nh, dh)
-    (c, n, m, hh), hs = _recurrent_over_chunks(step, state, xs, chunk)
+    with jax.named_scope(spans.SLSTM_SCAN):
+        (c, n, m, hh), hs = _recurrent_over_chunks(step, state, xs, chunk)
     hs = jnp.moveaxis(hs, 0, 1).reshape(bsz, l, d)
     out = ctx.linear("slstm_down", hs.astype(h.dtype), p["down"])
     if return_state:
