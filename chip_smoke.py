@@ -1,5 +1,5 @@
 """Chip smoke test: WTA-CRS fine-tuning of full-width xLSTM-125M on one
-TPU, checked against the plain-jnp backward, then serving from the
+TPU, checked against the plain-jnp kernel route, then serving from the
 trained weights.
 
     python chip_smoke.py                # one chip
@@ -10,8 +10,8 @@ Phases (one process; it starts no children):
   train      ``Run.fit`` of the published xlstm-125m config, WTA-CRS at
              budget 0.3, batch 4 x 512 tokens, 4 steps.  Every loss must
              be finite, and the compiled step must hold a
-             ``tpu_custom_call`` (the Pallas kernels ran natively, not
-             through the interpreter).
+             ``tpu_custom_call`` (the Pallas row-norm kernel ran
+             natively, not through the interpreter).
   reference  the same spec and seed with ``KernelConfig(backend="jnp")``.
              Step 0 builds its plans from the same weights and key on
              both routes (the forward is exact), so its gradients differ
@@ -291,9 +291,10 @@ def sharded_linear_phase(arch: str, reduced: bool, *, kernel=None,
                          seq: int = SEQ) -> dict:
     """One sampled linear (d_model -> 2 d_model, the run's batch and
     sequence) on the (data 2, model 2) mesh against one device, from the
-    same inputs and key: its plan, the per-data-shard kernel and the
-    kernel's psum over the data axes must give the one-device input and
-    weight gradients.  Return the gradient readings."""
+    same inputs and key: its plan (row norms per data shard) and the
+    partitioned dW contraction with its sum over the data axes must give
+    the one-device input and weight gradients.  Return the gradient
+    readings."""
     d = get_config(arch, reduced=reduced).d_model
     cfg = WTACRSConfig(kind="wta_crs", budget=0.3, min_rows=4)
     if kernel is not None:

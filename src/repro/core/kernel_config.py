@@ -11,6 +11,13 @@ one frozen, hashable record that rides inside ``WTACRSConfig`` (and
 therefore through policies, rules, and the ``RunSpec`` façade) and is
 consumed by every kernel dispatch site.
 
+On the training step the config routes one kernel: the row-norm pass
+that feeds the sampling plans (``core.plans.batched_row_weights``).
+The sampled weight gradient always runs as XLA's ``dot_general``
+(``core.linear._sampled_dw``); its Pallas kernel,
+``kernels.ops.fused_sampled_dw``, is a standalone op that still takes
+this config (blocks, tuning table) when called directly.
+
 Resolution:
 
 * ``interpret`` — ``None`` means "whether JAX's default backend is the
@@ -45,7 +52,8 @@ def _on_cpu() -> bool:
 
 @dataclasses.dataclass(frozen=True)
 class KernelConfig:
-    """How (and whether) the Pallas kernels serve a sampled linear.
+    """How (and whether) the Pallas kernels serve a sampled linear's
+    row norms, and the standalone kernel ops that take it.
 
     Attributes:
       backend: ``"auto"`` (Pallas when compiling natively, jnp under

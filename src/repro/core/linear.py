@@ -53,6 +53,7 @@ from repro import spans
 from repro.core import estimator_registry as registry
 from repro.core import plans
 from repro.core.config import WTACRSConfig
+from repro.kernels.fused_sampling import gather_scaled
 
 _EPS = 1e-30
 
@@ -73,8 +74,8 @@ def _make_plans(h, znorm, key_data, cfg: WTACRSConfig, k: int):
     Dispatches to the registered plan builder for ``cfg.kind``.  The
     znorm term enters the probabilities only under CACHED_GRAD (the
     config is authoritative; see NormSource).  The row-norm pass runs
-    through ``plans.batched_row_weights``, which shares ``cfg.kernel``
-    dispatch with the fused backward (Pallas row_norms kernel when the
+    through ``plans.batched_row_weights``, the one step of a sampled
+    linear that ``cfg.kernel`` routes (Pallas row_norms kernel when the
     config routes to Pallas).
     """
     weights = plans.batched_row_weights(h, znorm, cfg)        # (B, S)
@@ -92,23 +93,20 @@ def _rowgather(x: jax.Array, idx: jax.Array) -> jax.Array:
     return jax.vmap(lambda xb, ib: jnp.take(xb, ib, axis=0))(x, idx)
 
 
-def _sampled_dw(h_sub, dz, idx, scale, cfg: WTACRSConfig, out_dtype):
-    """dW = sum_b H'_b^T @ (dZ_b[idx_b] * scale_b).  Both routes gather
-    and scale the k rows of dZ the same way (``gather_scaled``); the
-    contraction is the Pallas kernel when ``cfg.kernel`` routes to
-    Pallas (blocks from the autotuner's tuning table), else one batched
-    dot_general.  Both run under the ``wtacrs_dw`` scope."""
+def _sampled_dw(h_sub, dz, idx, scale, out_dtype):
+    """dW = sum_b H'_b^T @ (dZ_b[idx_b] * scale_b): the k rows of dZ
+    gathered and scaled (``gather_scaled``, in dZ's dtype), then one
+    dot_general contracting (B, k) with f32 accumulation, under the
+    ``wtacrs_dw`` scope.  ``cfg.kernel`` does not route it: on a TPU
+    v5e XLA's dot runs this contraction an order of magnitude faster
+    than the Pallas kernel (``kernels.ops.fused_sampled_dw``, a
+    standalone op), and under a mesh the partitioner shards it over the
+    data axes and adds the cross-shard sum itself."""
     with jax.named_scope(spans.WTACRS_DW):
-        if cfg.kernel.use_pallas:
-            from repro.kernels import ops as kernel_ops
-            dw = kernel_ops.fused_sampled_dw(h_sub, dz, idx, scale,
-                                             kernel=cfg.kernel)
-        else:
-            from repro.kernels.fused_sampling import gather_scaled
-            dz_sub = gather_scaled(dz, idx, scale)             # (B, k, E)
-            dw = jax.lax.dot_general(
-                h_sub, dz_sub, (((0, 1), (0, 1)), ((), ())),
-                preferred_element_type=jnp.float32)
+        dz_sub = gather_scaled(dz, idx, scale)                 # (B, k, E)
+        dw = jax.lax.dot_general(
+            h_sub, dz_sub, (((0, 1), (0, 1)), ((), ())),
+            preferred_element_type=jnp.float32)
         return dw.astype(out_dtype)
 
 
@@ -135,7 +133,7 @@ def _sampled_linear_fwd(h, w, key_data, znorm, cfg: WTACRSConfig):
 def _sampled_linear_bwd(cfg: WTACRSConfig, residuals, dz):
     h_sub, idx, scale, w, key_shape = residuals
     dh = jnp.einsum("bse,de->bsd", dz, w)
-    dw = _sampled_dw(h_sub, dz, idx, scale, cfg, w.dtype)
+    dw = _sampled_dw(h_sub, dz, idx, scale, w.dtype)
     tap = _sq_norm_tap(dz)
     dkey = np.zeros(key_shape, dtype=jax.dtypes.float0)
     return dh.astype(h_sub.dtype), dw, dkey, tap
@@ -178,7 +176,7 @@ def _sampled_linear_shared_bwd(cfg: WTACRSConfig, residuals, dzs):
     dws = []
     tap = None
     for dz in dzs:
-        dws.append(_sampled_dw(h_sub, dz, idx, scale, cfg, ws[0].dtype))
+        dws.append(_sampled_dw(h_sub, dz, idx, scale, ws[0].dtype))
         t = _sq_norm_tap(dz)
         tap = t if tap is None else tap + t
     dkey = np.zeros(key_shape, dtype=jax.dtypes.float0)

@@ -171,9 +171,8 @@ def batched_row_weights(h: jax.Array, znorm: Optional[jax.Array],
     when ``cfg.norm_source == CACHED_GRAD`` (the config is
     authoritative — under ACTIVATION_ONLY a supplied znorm is ignored).
     The row norms run through the Pallas reduction kernel whenever
-    ``cfg.kernel`` routes to Pallas, so the plan-building pass shares
-    the same dispatch the fused backward uses; the fallback is an
-    f32-accumulating einsum (no materialized f32 copy of h).
+    ``cfg.kernel`` routes to Pallas; the fallback is an f32-accumulating
+    einsum (no materialized f32 copy of h).
     """
     from repro.core.config import NormSource
     kernel = getattr(cfg, "kernel", None)

@@ -16,6 +16,11 @@ Pallas exactly when compiling natively, jnp on interpret-mode/CPU
 backends).  Tests and CI pass ``PALLAS_INTERPRET_CONFIG`` to force the
 kernels through the interpreter.
 
+On the training step only :func:`row_norms` is reached (the plan's
+row-norm pass, ``core.plans.batched_row_weights``); the sampled weight
+gradient is XLA's dot_general in ``core.linear._sampled_dw``, and
+:func:`fused_sampled_dw` here is a standalone op with its tests.
+
 Padding policy: the legacy composition (``gather_scale`` /
 ``sampled_matmul``) pads operands to block multiples on the host (zero
 rows contribute nothing; padded index slots point at row 0 with scale
@@ -188,8 +193,10 @@ def fused_sampled_dw(hsub: jax.Array, dz: jax.Array, idx: jax.Array,
     """dW = sum_b hsub_b^T @ (dz_b[idx_b] * scale_b) via one XLA
     gather+scale fusion and the ragged-native contraction kernel.
 
-    Same contract as :func:`sampled_matmul`; this is the hot path
-    ``core.linear`` dispatches to.  Blocks come from the autotuner's
+    Same contract as :func:`sampled_matmul`.  A standalone op: the
+    training step's sampled linears compute the same contraction with
+    XLA's dot_general (``core.linear._sampled_dw``), which a TPU v5e
+    runs an order of magnitude faster.  Blocks come from the autotuner's
     tuning table keyed on (d_in, d_out, B, k, dtype) unless the config
     pins them.  Falls back to the jnp oracle when the config says so
     (backend ``jnp``, or ``auto`` on an interpret-mode backend).

@@ -229,12 +229,25 @@ def test_sampled_matmul_matches_linear_backward():
                                    rtol=1e-4, atol=1e-4)
 
 
+def _plan_dw(h, dz, key, cfg):
+    """The oracle dW of a sampled linear: the plan it builds from (h,
+    key, cfg), contracted by ``ref.sampled_matmul_batched_ref``."""
+    from repro.core import linear
+
+    k = cfg.budget_rows(h.shape[1])
+    idx, scale = linear._make_plans(h, jnp.ones(h.shape[:2], jnp.float32),
+                                    jax.random.key_data(key), cfg, k)
+    return ref.sampled_matmul_batched_ref(linear._rowgather(h, idx), dz,
+                                          idx, scale)
+
+
 @pytest.mark.kernel
 @pytest.mark.parametrize("batch", [1, 2, 8])
 def test_shared_backward_routes_through_kernel(batch):
-    """kernel=pallas must produce the same shared-plan dW gradients as
-    the jnp dot_general path for every batch size (per-weight AND
-    shared-plan paths both dispatch to the fused kernel)."""
+    """Shared-plan dW, under ``backend="pallas"`` and ``"jnp"`` alike,
+    equals the oracle contraction on the plan the linear built: the
+    backend routes only the plan's row norms, every dW is the same
+    gather and dot_general."""
     from repro.core.config import WTACRSConfig
     from repro.core.linear import wtacrs_linear_shared
 
@@ -244,22 +257,26 @@ def test_shared_backward_routes_through_kernel(batch):
     w2 = jnp.asarray(rng.randn(32, 16) * 0.1, jnp.float32)
     key = jax.random.PRNGKey(5)
 
-    def loss(ws, backend):
+    for backend in ("pallas", "jnp"):
         cfg = WTACRSConfig(budget=0.25, min_rows=4,
                            kernel=KernelConfig(backend=backend))
-        a, b = wtacrs_linear_shared(h, ws, key=key, cfg=cfg)
-        return jnp.sum(jnp.sin(a)) + jnp.sum(jnp.cos(b))
 
-    g_jnp = jax.grad(lambda ws: loss(ws, "jnp"))((w1, w2))
-    g_ker = jax.grad(lambda ws: loss(ws, "pallas"))((w1, w2))
-    for gj, gk in zip(g_jnp, g_ker):
-        np.testing.assert_allclose(np.asarray(gk), np.asarray(gj),
-                                   rtol=1e-4, atol=1e-4)
+        def loss(ws):
+            a, b = wtacrs_linear_shared(h, ws, key=key, cfg=cfg)
+            return jnp.sum(jnp.sin(a)) + jnp.sum(jnp.cos(b))
+
+        g1, g2 = jax.grad(loss)((w1, w2))
+        want1 = _plan_dw(h, jnp.cos(h @ w1), key, cfg)
+        want2 = _plan_dw(h, -jnp.sin(h @ w2), key, cfg)
+        for got, want in ((g1, want1), (g2, want2)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.kernel
 def test_per_weight_backward_routes_through_kernel():
-    """Per-weight path: fused-kernel grads == jnp grads."""
+    """Per-weight dW, under ``backend="pallas"`` and ``"jnp"`` alike,
+    equals the oracle contraction on the plan the linear built."""
     from repro.core.config import WTACRSConfig
     from repro.core.linear import wtacrs_linear
 
@@ -268,15 +285,14 @@ def test_per_weight_backward_routes_through_kernel():
     w = jnp.asarray(rng.randn(24, 20) * 0.1, jnp.float32)
     key = jax.random.PRNGKey(9)
 
-    def loss(w, backend):
+    for backend in ("pallas", "jnp"):
         cfg = WTACRSConfig(budget=0.3, min_rows=4,
                            kernel=KernelConfig(backend=backend))
-        return jnp.sum(wtacrs_linear(h, w, key=key, cfg=cfg) ** 2)
-
-    g_jnp = jax.grad(lambda w: loss(w, "jnp"))(w)
-    g_ker = jax.grad(lambda w: loss(w, "pallas"))(w)
-    np.testing.assert_allclose(np.asarray(g_ker), np.asarray(g_jnp),
-                               rtol=1e-4, atol=1e-4)
+        got = jax.grad(lambda w_: jnp.sum(
+            wtacrs_linear(h, w_, key=key, cfg=cfg) ** 2))(w)
+        want = _plan_dw(h, 2 * (h @ w), key, cfg)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_use_kernel_deprecated_alias():
@@ -381,11 +397,9 @@ print("per-shard ok")
 """
 
 
-def test_kernels_run_per_data_shard_under_mesh():
-    """Under a multi-device mesh the kernels run inside shard_map over
-    the data axes (the TPU compiler cannot partition a Mosaic kernel):
-    the per-shard partial dW sums back to the whole, and row norms keep
-    their row split.  Four virtual CPU devices need a fresh process."""
+def _run_on_four_devices(script):
+    """Run ``script`` in a fresh process on four virtual CPU devices;
+    return its standard output."""
     import os
     import subprocess
     import sys
@@ -394,8 +408,68 @@ def test_kernels_run_per_data_shard_under_mesh():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(root, "src"))
-    proc = subprocess.run([sys.executable, "-c", _PER_SHARD_SCRIPT],
+    proc = subprocess.run([sys.executable, "-c", script],
                           env=env, capture_output=True, text=True,
                           timeout=300)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert "per-shard ok" in proc.stdout
+    return proc.stdout
+
+
+def test_kernels_run_per_data_shard_under_mesh():
+    """Under a multi-device mesh the kernels run inside shard_map over
+    the data axes (the TPU compiler cannot partition a Mosaic kernel):
+    the per-shard partial dW sums back to the whole, and row norms keep
+    their row split.  Four virtual CPU devices need a fresh process."""
+    assert "per-shard ok" in _run_on_four_devices(_PER_SHARD_SCRIPT)
+
+
+_SHARDED_DW_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.config import WTACRSConfig
+from repro.core.kernel_config import KernelConfig
+from repro.core.linear import wtacrs_linear, wtacrs_linear_shared
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 2), ("data", "model"))
+rng = np.random.RandomState(0)
+h = jnp.asarray(rng.randn(4, 32, 64), jnp.float32)
+ws = tuple(jnp.asarray(rng.randn(64, e) * 0.1, jnp.float32)
+           for e in (48, 32))
+key = jax.random.PRNGKey(3)
+for backend in ("jnp", "pallas"):
+    cfg = WTACRSConfig(budget=0.25, min_rows=4,
+                       kernel=KernelConfig(backend=backend))
+
+    def per_weight(h, ws):
+        return sum(jnp.sum(jnp.sin(wtacrs_linear(h, w, key=key, cfg=cfg)))
+                   for w in ws)
+
+    def shared(h, ws):
+        zs = wtacrs_linear_shared(h, ws, key=key, cfg=cfg)
+        return sum(jnp.sum(jnp.sin(z)) for z in zs)
+
+    for loss in (per_weight, shared):
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+        want = jax.device_get(grad(h, ws))
+        with jax.set_mesh(mesh):
+            hs = jax.device_put(h, NamedSharding(mesh, P("data")))
+            wss = tuple(jax.device_put(w, NamedSharding(mesh,
+                                                        P(None, "model")))
+                        for w in ws)
+            assert "fused_sampled_dw" not in str(
+                jax.make_jaxpr(grad)(hs, wss))
+            got = jax.device_get(grad(hs, wss))
+        for g, w_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                       rtol=1e-4, atol=1e-4)
+print("sharded dW ok")
+"""
+
+
+def test_sampled_dw_partitions_under_mesh():
+    """The sampled linears' gradients (per-weight and shared-plan, either
+    backend) under a data-sharded batch on a (data 2, model 2) mesh
+    equal the unsharded ones: the partitioner shards the dW dot_general
+    over the data axes and adds the cross-shard sum itself."""
+    assert "sharded dW ok" in _run_on_four_devices(_SHARDED_DW_SCRIPT)

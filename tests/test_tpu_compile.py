@@ -1,4 +1,5 @@
-"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+"""Compile-only checks of the Pallas kernels for a TPU v5e: the training
+step's row-norm kernel and the standalone sampled-dW op.
 
 The TPU compiler is installed with JAX and compiles for a described
 (not attached) ``v5e:2x2`` topology, so these tests catch what
@@ -19,9 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.kernel_config import KernelConfig
 from repro.kernels import ops
 
-# What a kernel on the main path looks like on a TPU: ``use_pallas``
-# with native compilation (the CPU backend here would resolve
-# ``interpret=True``).
+# What a kernel looks like on a TPU: ``use_pallas`` with native
+# compilation (the CPU backend here would resolve ``interpret=True``).
 TPU_KERNELS = KernelConfig(backend="pallas", interpret=False)
 
 
