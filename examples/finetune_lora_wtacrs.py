@@ -55,13 +55,14 @@ def main():
             "*mlp*", base,
             ESSProportional(b_min=0.1, b_max=0.6, levels=6, warmup=3)))
     elif args.warmup_exact > 0:
-        # MoE routers sample the flattened-rows dim: the per-sample
-        # gradient-norm cache has no column for them (PT003), so they
-        # take activation norms while everything else uses the cache.
-        router = dataclasses.replace(
+        # MoE routers and expert-share linears sample flattened rows:
+        # the per-sample gradient-norm cache has no column for them
+        # (PT003), so they take activation norms while everything else
+        # uses the cache.
+        rows = dataclasses.replace(
             base, norm_source=NormSource.ACTIVATION_ONLY)
         rules = PolicyRules.of(
-            ("*moe_router", router),
+            ("*moe_*", rows),
             ("*", base, BudgetSchedule.warmup_exact(
                 begin_step=args.warmup_exact, end=args.budget)))
     policy = cm.Policy(

@@ -153,7 +153,7 @@ class Run:
                 "this run's policy needs the znorm cache, so every "
                 "batch must carry 'sample_ids' (dataset sample indices; "
                 "DataSpec-built datasets provide them)")
-        s = int(self.state["step"])
+        s = self.step_fn.host_step(self.state)
         # the ambient mesh lets the Pallas kernels run per data shard
         # (kernels.ops); the compiler cannot partition them itself
         mesh = (jax.set_mesh(self.mesh) if self.mesh is not None
@@ -161,7 +161,7 @@ class Run:
         with mesh, spans.host_span(spans.STEP_DISPATCH):
             self.state, metrics = self.step_fn(self.state, b)
         with spans.host_span(spans.STEP_FETCH):
-            m = {k: float(v) for k, v in metrics.items()}
+            m = {k: float(v) for k, v in jax.device_get(metrics).items()}
         self.history.append({"step": s, **m})
         return m
 

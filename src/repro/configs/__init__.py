@@ -12,7 +12,7 @@ __all__ = ["ArchConfig", "InputShape", "SHAPES", "shape_applicable",
 _ARCH_MODULES = [
     "dbrx_132b", "granite_moe_1b_a400m", "nemotron_4_15b", "qwen2_5_3b",
     "command_r_35b", "minicpm_2b", "qwen2_vl_2b", "xlstm_125m",
-    "whisper_base", "zamba2_2_7b",
+    "whisper_base", "zamba2_2_7b", "kanana_2_30b_a3b",
 ]
 
 

@@ -35,9 +35,25 @@ class ArchConfig:
     # mlp
     mlp_type: str = "swiglu"       # swiglu | gelu | relu2
     # moe
-    n_experts: int = 0
+    n_experts: int = 0             # experts held here (the chip's share)
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0              # expert width; 0 -> d_ff
+    n_shared_experts: int = 0      # run for every token: one SwiGLU of
+    #                                n_shared_experts * moe_d_ff
+    router_experts: int = 0        # experts routed over; 0 -> n_experts
+    expert_offset: int = 0         # held: [offset, offset + n_experts)
+    router: str = "softmax"        # softmax (capacity dispatch) | sigmoid
+    #                                (DeepSeek-V3 noaux_tc, no drops)
+    routed_scaling: float = 1.0    # sigmoid router's weight scale
+    first_dense_layers: int = 0    # leading dense layers (in n_layers),
+    #                                run once before the scanned pattern
+    # latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank 0 = none
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # rotate (x0,x1),(x2,x3).. pairs
     # ssm / recurrent (mamba2, xlstm)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -64,10 +80,32 @@ class ArchConfig:
 
     @property
     def n_repeats(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
+        n = self.n_layers - self.first_dense_layers
+        assert n % len(self.pattern) == 0, (
+            f"{self.name}: n_layers={self.n_layers} less "
+            f"{self.first_dense_layers} dense not divisible by "
             f"pattern of length {len(self.pattern)}")
-        return self.n_layers // len(self.pattern)
+        return n // len(self.pattern)
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Query/key head width (MLA: nope + rope parts)."""
+        if self.is_mla:
+            return self.qk_nope_dim + self.qk_rope_dim
+        return self.head_dim
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_routed(self) -> int:
+        """Width of the router: the experts every token chooses among."""
+        return self.router_experts or self.n_experts
 
     @property
     def pdtype(self):
@@ -86,23 +124,43 @@ class ArchConfig:
         """Sub-quadratic (state-based) sequence mixing => long_500k runs."""
         return any(b in ("mamba", "mlstm", "slstm") for b in self.pattern)
 
+    def _attn_params(self) -> int:
+        d, h = self.d_model, self.n_heads
+        if self.is_mla:
+            r, dn, dv = self.kv_lora_rank, self.qk_nope_dim, self.v_head_dim
+            return (d * h * self.qk_head_dim + d * (r + self.qk_rope_dim)
+                    + r + r * h * (dn + dv) + h * dv * d)
+        dh = self.head_dim
+        return d * (h * dh) + 2 * d * (self.n_kv_heads * dh) + (h * dh) * d
+
+    def _mlp_params(self, d_ff: int) -> int:
+        return (3 if self.mlp_type == "swiglu" else 2) * self.d_model * d_ff
+
+    def _moe_params(self, n_experts: int) -> int:
+        """One expert layer holding ``n_experts``: router (and its bias
+        for a sigmoid router), experts, shared experts."""
+        d, f = self.d_model, self.expert_d_ff
+        router = d * self.n_routed
+        if self.router == "sigmoid":
+            router += self.n_routed
+        shared = (self._mlp_params(self.n_shared_experts * f)
+                  if self.n_shared_experts else 0)
+        return router + n_experts * 3 * d * f + shared
+
     def n_params(self) -> int:
-        """Approximate parameter count (used for 6ND model-FLOPs)."""
-        d, dff, dh = self.d_model, self.d_ff, self.head_dim
-        per_attn = d * (self.n_heads * dh) + 2 * d * (self.n_kv_heads * dh) \
-            + (self.n_heads * dh) * d
-        if self.mlp_type == "swiglu":
-            per_mlp = 3 * d * dff
-        else:
-            per_mlp = 2 * d * dff
-        total = 0
+        """Parameters held here (the experts of this share), norms
+        left out (used for 6ND model-FLOPs)."""
+        d = self.d_model
+        per_attn = self._attn_params()
+        per_mlp = self._mlp_params(self.d_ff)
+        total = self.first_dense_layers * (per_attn + per_mlp)
         for b in self.pattern * self.n_repeats:
             if b in ("attn", "xattn", "shared_attn"):
                 total += per_attn + per_mlp
                 if b == "xattn":
                     total += per_attn  # cross-attention projections
             elif b == "attn_moe":
-                total += per_attn + self.n_experts * 3 * d * dff
+                total += per_attn + self._moe_params(self.n_experts)
             elif b == "mamba":
                 d_in = self.ssm_expand * d
                 # in_proj (d -> 2*di + 2*N + H), conv, out_proj
@@ -128,13 +186,14 @@ class ArchConfig:
         return total
 
     def n_active_params(self) -> int:
-        """Active parameters per token (MoE: only top-k experts count)."""
+        """Active parameters per token (MoE: the top-k experts count,
+        wherever they are held, and the shared experts)."""
         if self.n_experts == 0:
             return self.n_params()
-        d, dff = self.d_model, self.d_ff
-        dense_experts = self.n_layers * self.n_experts * 3 * d * dff
-        active_experts = self.n_layers * self.moe_top_k * 3 * d * dff
-        return self.n_params() - dense_experts + active_experts
+        n_moe = sum(b == "attn_moe" for b in self.pattern) * self.n_repeats
+        expert = 3 * self.d_model * self.expert_d_ff
+        return self.n_params() + n_moe * expert * (self.moe_top_k
+                                                   - self.n_experts)
 
 
 @dataclasses.dataclass(frozen=True)

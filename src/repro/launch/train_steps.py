@@ -363,8 +363,9 @@ class ScheduledStepFn:
 
     Sampling budgets fix static residual shapes, so a schedule cannot be
     traced — instead the policy is re-resolved at the CONCRETE step read
-    from ``state["step"]`` (one host sync per step, same cost class as
-    reading metrics) and one compiled step is cached per resolved
+    from ``state["step"]`` (a host sync, skipped when the counter is the
+    one this driver's last call returned: see ``host_step``) and one
+    compiled step is cached per resolved
     schedule signature.  Piecewise-constant schedules therefore bound
     the number of recompiles by their plateau count; schedule-free
     policies compile exactly once.
@@ -404,6 +405,7 @@ class ScheduledStepFn:
         self._jit = jit
         self._train_step_kwargs = train_step_kwargs
         self.compiled: Dict[tuple, Callable] = {}
+        self._last_step = None   # (the step counter last returned, its int)
 
         rules = policy.rules.rules if policy.rules is not None else ()
         self._rules = rules
@@ -486,8 +488,17 @@ class ScheduledStepFn:
                         break
         return self.owned_tags
 
+    def host_step(self, state) -> int:
+        """``state["step"]`` as an int.  When it is the very counter this
+        driver's last call returned, its value is known (a step adds one)
+        and the device is not asked: arrays are immutable."""
+        last = self._last_step
+        if last is not None and state["step"] is last[0]:
+            return last[1]
+        return int(state["step"])
+
     def __call__(self, state, batch):
-        step = int(state["step"])
+        step = self.host_step(state)
         st = self.schedule_state
         rule_budgets = None
         stats_host = None
@@ -544,7 +555,9 @@ class ScheduledStepFn:
             if self._jit:
                 fn = jax.jit(fn)
             self.compiled[sig] = fn
-        return fn(state, batch)
+        new_state, metrics = fn(state, batch)
+        self._last_step = (new_state["step"], step + 1)
+        return new_state, metrics
 
     def _apply_rank_dynamics(self, state, step: int, stats_host):
         """Resolve rank schedules/controllers at the concrete step and

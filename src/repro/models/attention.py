@@ -37,13 +37,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, q_block: int = 512,
                     kv_block: int = 512, mode: str = "full",
                     q_offset: int = 0) -> jax.Array:
-    """q: (B, Sq, H, Dh); k, v: (B, Skv, KVH, Dh).  Returns (B, Sq, H, Dh).
+    """q, k: (B, Sq|Skv, H|KVH, Dh); v: (B, Skv, KVH, Dv).  Returns
+    (B, Sq, H, Dv); scores are scaled by 1/sqrt(Dh), the q/k width (MLA
+    has Dv != Dh).
 
     ``q_offset``: absolute position of q[0] (for chunked prefill).
     """
     with jax.named_scope(spans.ATTENTION):
         b, sq, h, dh = q.shape
-        skv, kvh = k.shape[1], k.shape[2]
+        skv, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
         g = h // kvh
         q_block = min(q_block, sq)
         kv_block = min(kv_block, skv)
@@ -54,7 +56,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qr = q.reshape(b, nq, q_block, kvh, g, dh)
         qr = jnp.moveaxis(qr, 1, 0)                 # (nq, B, bq, KVH, G, Dh)
         kr = jnp.moveaxis(k.reshape(b, nk, kv_block, kvh, dh), 1, 0)
-        vr = jnp.moveaxis(v.reshape(b, nk, kv_block, kvh, dh), 1, 0)
+        vr = jnp.moveaxis(v.reshape(b, nk, kv_block, kvh, dv), 1, 0)
 
         q_pos_base = jnp.arange(q_block) + q_offset
         k_pos_base = jnp.arange(kv_block)
@@ -86,7 +88,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         def init_state():
             m = jnp.full((b, kvh, g, q_block), NEG_INF, jnp.float32)
             l = jnp.zeros((b, kvh, g, q_block), jnp.float32)
-            acc = jnp.zeros((b, kvh, g, q_block, dh), jnp.float32)
+            acc = jnp.zeros((b, kvh, g, q_block, dv), jnp.float32)
             return m, l, acc
 
         if mode == "triangular":
@@ -95,7 +97,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             kj_arr = jnp.array([p[1] for p in pairs], jnp.int32)
             last_arr = jnp.array([p[2] for p in pairs], jnp.bool_)
 
-            out0 = jnp.zeros((nq, b, kvh, g, q_block, dh), jnp.float32)
+            out0 = jnp.zeros((nq, b, kvh, g, q_block, dv), jnp.float32)
 
             def step(carry, xs):
                 m, l, acc, out = carry
@@ -137,9 +139,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             _, o = jax.lax.scan(q_step, None,
                                 (qr, jnp.arange(nq, dtype=jnp.int32)))
 
-        # (nq, B, KVH, G, bq, Dh) -> (B, Sq, H, Dh)
+        # (nq, B, KVH, G, bq, Dv) -> (B, Sq, H, Dv)
         o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 4, 2, 3, 5)
-        o = o.reshape(b, sq, h, dh)
+        o = o.reshape(b, sq, h, dv)
         return o.astype(q.dtype)
 
 
@@ -158,7 +160,7 @@ def attention_reference(q, k, v, *, causal=True, q_offset: int = 0):
         s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bhgqd", p, v.astype(jnp.float32))
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dh)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, v.shape[-1])
     return o.astype(q.dtype)
 
 
@@ -166,7 +168,8 @@ def decode_attention(q1: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_len: jax.Array) -> jax.Array:
     """Single-token attention against a (B, Smax, KVH, Dh) KV cache.
 
-    q1: (B, 1, H, Dh).  cache_len: scalar or (B,) number of valid positions
+    q1: (B, 1, H, Dh); the v cache's last dim may differ from Dh (MLA).
+    cache_len: scalar or (B,) number of valid positions
     (the new token's K/V must already be written at cache_len-1).
 
     Numerics mirror ``flash_attention``'s block step exactly: scores and
@@ -196,4 +199,4 @@ def decode_attention(q1: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     pv = jnp.einsum("bhgk,bkhd->bhgd", p.astype(q1.dtype),
                     v_cache).astype(jnp.float32)
     o = pv / jnp.maximum(l, 1e-30)[..., None]
-    return o.reshape(b, 1, h, dh).astype(q1.dtype)
+    return o.reshape(b, 1, h, v_cache.shape[-1]).astype(q1.dtype)

@@ -129,6 +129,21 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float) -> jax.Array:
+    """RoPE on adjacent pairs: (x[2i], x[2i+1]) turns by position *
+    theta^(-2i/Dh) (DeepSeek-V3's ``rope_interleave``).  x: (B, S, H, Dh);
+    positions: (B, S) int32."""
+    freqs = rope_frequencies(x.shape[-1], theta)
+    angles = positions[..., None].astype(jnp.float32) * freqs   # (B,S,Dh/2)
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    x1, x2 = xr[..., 0], xr[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def apply_mrope(x: jax.Array, positions3: jax.Array, theta: float,
                 sections: Tuple[int, int, int] = None) -> jax.Array:
     """Qwen2-VL multimodal RoPE: positions3 (3, B, S) = (t, h, w) ids.

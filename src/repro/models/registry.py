@@ -91,6 +91,12 @@ def serve_compatible(cfg: ArchConfig) -> Tuple[bool, str]:
             "cross-attention cache and a shared scalar position, which "
             "the ragged slot pool cannot provide; serve decoder-only "
             "LMs (dense/MoE/SSM/hybrid/VLM)")
+    if cfg.is_mla:
+        return False, (
+            "latent attention (MLA): the slot pool pages one (KVH, Dh) "
+            "geometry for both K and V, while MLA caches a kv_lora_rank-"
+            "wide latent and one shared rope key per token; serving MLA "
+            "is out of scope")
     return True, ""
 
 

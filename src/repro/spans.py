@@ -26,6 +26,10 @@ WTACRS_DW = "wtacrs_dw"        # the sampled weight-gradient contraction
 ATTENTION = "attention"        # flash attention
 MLSTM_SCAN = "mlstm_scan"      # the mLSTM recurrence over tokens
 SLSTM_SCAN = "slstm_scan"      # the sLSTM recurrence over tokens
+MOE_ROUTE = "moe_route"        # router, expert selection and weights
+MOE_DISPATCH = "moe_dispatch"  # sort, gather of the held experts' rows,
+#                                and the weighted combine
+MOE_EXPERTS = "moe_experts"    # the held experts' GEMMs and their dW
 
 # host spans of Run.step
 STEP_PUT = "repro.step.put"            # the batch's upload

@@ -265,6 +265,21 @@ class TestScheduleState:
         rec = step.budget_trajectory[0]
         assert rec["step"] == 5 and rec["prev"] is None
 
+    def test_host_step_known_for_the_counter_it_returned(self):
+        """The driver knows the counter its last call returned without
+        reading it from the device; any other counter is read."""
+        run = Run(_spec(_plain_policy()))
+        run.fit(steps=2)
+        fn = run.step_fn
+        assert fn._last_step[0] is run.state["step"]
+        assert fn.host_step(run.state) == 2 == int(run.state["step"])
+        assert [h["step"] for h in run.history] == [0, 1]
+        run.state = dict(run.state, step=jnp.asarray(9, jnp.int32))
+        assert fn.host_step(run.state) == 9
+        run.step(run.dataset.batch_at(9, 4))
+        assert run.history[-1]["step"] == 9
+        assert fn.host_step(run.state) == 10 == int(run.state["step"])
+
 
 class TestRunStateRecord:
     def test_missing_record_is_none(self):

@@ -23,6 +23,9 @@ SCOPES = {
                    spans.WTACRS_DW, spans.MLSTM_SCAN, spans.SLSTM_SCAN),
     "minicpm-2b": (spans.FORWARD, spans.OPTIMIZER, spans.WTACRS_PLAN,
                    spans.WTACRS_DW, spans.ATTENTION),
+    "kanana-2-30b-a3b": (spans.FORWARD, spans.OPTIMIZER, spans.WTACRS_PLAN,
+                         spans.WTACRS_DW, spans.ATTENTION, spans.MOE_ROUTE,
+                         spans.MOE_DISPATCH, spans.MOE_EXPERTS),
 }
 
 
@@ -87,6 +90,21 @@ def test_backward_ops_carry_the_transposed_forward(stepped):
     assert any(BACKWARD not in n for n in plan)
     opt = [n for n in names if _under(n, spans.OPTIMIZER)]
     assert opt and not any(_under(n, spans.FORWARD) for n in opt)
+
+
+def test_expert_gemms_and_their_dw_sit_under_moe_experts(stepped):
+    """The held experts' weight gradients run in the backward inside
+    ``moe_experts``; routing and dispatch are scopes of their own."""
+    arch, names, _ = stepped
+    if spans.MOE_EXPERTS not in SCOPES[arch]:
+        assert not any(_under(n, spans.MOE_EXPERTS) for n in names)
+        return
+    experts = [n for n in names if _under(n, spans.MOE_EXPERTS)]
+    assert any(_under(n, spans.WTACRS_DW) and BACKWARD in n
+               for n in experts)
+    assert any(BACKWARD not in n for n in experts)
+    assert not any(_under(n, spans.MOE_DISPATCH) or _under(n, spans.MOE_ROUTE)
+                   for n in experts)
 
 
 def test_run_step_opens_its_host_spans(stepped):
