@@ -6,9 +6,12 @@ algorithm and the TPU-friendly one: intra-chunk work is MXU einsums,
 the inter-chunk recurrence is a short scan over L/chunk steps.
 
 mLSTM/sLSTM (xLSTM, arXiv:2405.04517) use exponential gating with the
-log-space max-stabilizer m_t.  Training runs an outer scan over sequence
-chunks with the inner chunk rematerialized, so backward stores only
-chunk-boundary states.
+log-space max-stabilizer m_t.  The mLSTM is linear in its matrix memory,
+so a sequence runs chunkwise-parallel like Mamba2: masked matmuls inside
+a chunk, one carried state across chunks (xLSTM's own training form,
+arXiv:2405.04517 App. B).  The sLSTM is non-linear in h and runs an
+outer scan over sequence chunks with the inner chunk rematerialized, so
+backward stores only chunk-boundary states.
 
 All in/out projections route through ctx.linear and are therefore
 WTA-CRS-compressible; the recurrences themselves are not weight GEMMs
@@ -251,7 +254,89 @@ def _recurrent_over_chunks(cell_step, state, xs_seq, chunk: int):
     return state, ys
 
 
-def apply_mlstm(cfg, p, ctx: cm.Ctx, h, chunk: int = 256,
+def _mm(spec, *operands):
+    """einsum at f32 accuracy: bf16 operands multiply exactly, f32 ones
+    take the MXU's multi-pass route; every sum accumulates in f32."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _mlstm_chunkwise(state, q, k, v, i_raw, logf, chunk: int):
+    """The stabilized mLSTM over a sequence, chunkwise-parallel.
+
+    q, k, v: (B, L, H, dh); i_raw, logf (log forget gate): (B, L, H) f32;
+    state (C, n, m) as in ``_mlstm_cell_step``.  Returns the state after
+    the last token and h: (B, L, H, dh) f32, as scanning
+    ``_mlstm_cell_step`` over the tokens would, up to rounding.
+
+    With F the cumulative log forget gate from a chunk's start and (C, n,
+    m) the state carried into it, unstabilized C_t = e^(F_t + m) C +
+    sum_{s<=t} e^(D_ts) k_s v_s^T / sqrt(dh), D_ts = F_t - F_s + i_s, and
+    the recurrent stabilizer m_t = max(logf_t + m_{t-1}, i_t) unrolls to
+    the row maximum of (F_t + m, D_t.).  So each token's h_t is computed
+    with its own m_t, and the chunk's last row is the state at its end.
+    The chunk's body is rematerialized: the backward keeps only the
+    carried state at each chunk boundary and the chunk's inputs, never a
+    per-token (dh, dh) memory, and rebuilds the (c, c) and (c, dh) blocks
+    from them (on a v5e that is faster than storing them, and smaller).
+    A tail past the last whole chunk is padded with gates that add
+    nothing (i = -inf, logf = 0), so it moves neither h nor the final
+    state.
+    """
+    bsz, l, nh, dh = q.shape
+    chunk = min(chunk, l)
+    nc = -(-l // chunk)
+    pad = nc * chunk - l
+    if pad:
+        tail = lambda x, val: jnp.pad(
+            x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2),
+            constant_values=val)
+        q, k, v = tail(q, 0), tail(k, 0), tail(v, 0)
+        i_raw, logf = tail(i_raw, -jnp.inf), tail(logf, 0.0)
+    # (B, L, H, ...) -> (nc, B, H, c, ...)
+    to_chunks = lambda x: jnp.moveaxis(
+        x.reshape((bsz, nc, chunk) + x.shape[2:]), (1, 3), (0, 2))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    after = jnp.tril(causal, -1)                 # (r, s): r > s
+    scale = 1.0 / math.sqrt(dh)
+
+    @jax.checkpoint
+    def step(carry, xs):
+        c, n, m = carry                          # (B,H,dh,dh), (B,H,dh), (B,H)
+        qc, kc, vc, ic, fc = xs                  # (B,H,c,dh) x3, (B,H,c) x2
+        fcum = jnp.cumsum(fc, axis=-1)
+        # F_t - F_s as a sum over s < r <= t of its own, not a difference
+        # of two prefixes: no cancellation, in the values or the backward
+        seg = jnp.cumsum(jnp.where(after, fc[..., :, None], 0.0), axis=-2)
+        log_d = seg + ic[..., None, :]
+        m_t = jnp.maximum(fcum + m[..., None],
+                          jnp.max(jnp.where(causal, log_d, -jnp.inf), -1))
+        # double-where: masked decays (s > t) can be large and positive;
+        # they never reach exp, so the backward has no 0 * inf
+        decay = jnp.where(
+            causal, jnp.exp(jnp.where(causal, log_d - m_t[..., None], 0.0)),
+            0.0)                                 # (B,H,t,s) <= 1
+        carried = jnp.exp(fcum + m[..., None] - m_t)   # (B,H,t) <= 1
+        p = _mm("bhtd,bhsd->bhts", qc, kc) * scale * decay
+        num = (_mm("bhts,bhsd->bhtd", p, vc)
+               + carried[..., None] * _mm("bhtd,bhde->bhte", qc, c))
+        qn = p.sum(-1) + carried * _mm("bhtd,bhd->bht", qc, n)
+        h = num / jnp.maximum(jnp.abs(qn), jnp.exp(-m_t))[..., None]
+
+        w_end = decay[..., -1, :] * scale        # (B,H,s)
+        f_end = carried[..., -1]                 # (B,H)
+        c = f_end[..., None, None] * c + _mm(
+            "bhsd,bhse->bhde", kc * w_end[..., None], vc)
+        n = f_end[..., None] * n + _mm("bhs,bhsd->bhd", w_end, kc)
+        return (c, n, m_t[..., -1]), h
+
+    state, hs = jax.lax.scan(
+        step, state, tuple(map(to_chunks, (q, k, v, i_raw, logf))))
+    hs = jnp.moveaxis(hs, (0, 2), (1, 3)).reshape(bsz, nc * chunk, nh, dh)
+    return state, hs[:, :l]
+
+
+def apply_mlstm(cfg, p, ctx: cm.Ctx, h, chunk: int = 128,
                 return_state: bool = False):
     bsz, l, d = h.shape
     di, nh, dh = mlstm_dims(cfg)
@@ -264,16 +349,12 @@ def apply_mlstm(cfg, p, ctx: cm.Ctx, h, chunk: int = 256,
            + p["if_bias"][None, None, :])
     i_raw, f_raw = jnp.split(gif, 2, axis=-1)           # (B,L,H)
 
-    to_seq = lambda x: jnp.moveaxis(x.astype(jnp.float32), 1, 0)
     state = mlstm_decode_init(cfg, bsz)
     state = (state["c"], state["n"], state["m"])
     with jax.named_scope(spans.MLSTM_SCAN):
-        (cs, ns, ms), hs = _recurrent_over_chunks(
-            _mlstm_cell_step, state,
-            (to_seq(q), to_seq(k), to_seq(v), to_seq(i_raw),
-             to_seq(f_raw)),
-            chunk)
-    hs = jnp.moveaxis(hs, 0, 1).reshape(bsz, l, di)     # (B,L,di)
+        (cs, ns, ms), hs = _mlstm_chunkwise(
+            state, q, k, v, i_raw, -jax.nn.softplus(-f_raw), chunk)
+    hs = hs.reshape(bsz, l, di)
     y = hs.astype(h.dtype) * jax.nn.silu(z)
     out = ctx.linear("mlstm_down", y, p["down"])
     if return_state:
