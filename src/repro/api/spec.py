@@ -83,9 +83,8 @@ class RunSpec:
     ``kernel``: optional :class:`~repro.core.kernel_config.KernelConfig`
     applied to EVERY estimator config the policy can resolve to
     (``Policy.with_kernel``) before the run is assembled — one switch
-    for backend (``auto|pallas|jnp``), block overrides, and the
-    autotune tuning table.  ``None`` keeps whatever each config
-    already carries.
+    for the row-norm backend (``auto|pallas|jnp``) and interpret mode.
+    ``None`` keeps whatever each config already carries.
     """
 
     arch: str

@@ -14,11 +14,9 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import warnings
 from typing import Union
 
-from repro.core.kernel_config import (DEFAULT_KERNEL_CONFIG,  # noqa: F401
-                                      PALLAS_INTERPRET_CONFIG, KernelConfig)
+from repro.core.kernel_config import DEFAULT_KERNEL_CONFIG, KernelConfig
 
 
 class EstimatorKind(str, enum.Enum):
@@ -69,13 +67,9 @@ class WTACRSConfig:
       deterministic_fraction_cap: upper bound on |C|/k.  1.0 reproduces the
         paper exactly (|C| chosen by Theorem 2); smaller values force some
         stochastic budget, useful for ablations.
-      kernel: unified kernel-dispatch config (:class:`KernelConfig`) —
-        backend selection (``auto | pallas | jnp``), block overrides,
-        autotune on/off, the tuning-table path and interpret mode.
-      use_kernel: DEPRECATED alias for
-        ``kernel=KernelConfig(backend="pallas")``; kept so old call
-        sites keep routing through the Pallas kernels (a
-        DeprecationWarning points at the replacement).
+      kernel: kernel-dispatch config (:class:`KernelConfig`) — whether
+        the plans' row norms run on the Pallas kernel (``auto | pallas
+        | jnp``) and interpret mode.
     """
 
     kind: Union[EstimatorKind, str] = EstimatorKind.WTA_CRS
@@ -84,23 +78,12 @@ class WTACRSConfig:
     min_rows: int = 8
     deterministic_fraction_cap: float = 1.0
     kernel: KernelConfig = DEFAULT_KERNEL_CONFIG
-    use_kernel: bool = False
 
     def __post_init__(self):
         # kind is open (any registered name; validated at dispatch), but
         # norm_source is a closed set — reject typos here instead of
         # letting them silently disable the gradient-norm cache.
         object.__setattr__(self, "norm_source", NormSource(self.norm_source))
-        # Deprecated alias: use_kernel=True forced the Pallas path.  Map
-        # it onto the unified config once (an already-pallas backend is
-        # left alone, so dataclasses.replace round-trips don't re-fire).
-        if self.use_kernel and self.kernel.backend == "auto":
-            warnings.warn(
-                "WTACRSConfig(use_kernel=True) is deprecated; pass "
-                "kernel=KernelConfig(backend='pallas') instead",
-                DeprecationWarning, stacklevel=2)
-            object.__setattr__(self, "kernel",
-                               self.kernel.with_backend("pallas"))
 
     @property
     def kind_name(self) -> str:
@@ -126,9 +109,8 @@ class WTACRSConfig:
         return dataclasses.replace(self, budget=budget)
 
     def with_kernel(self, kernel: KernelConfig) -> "WTACRSConfig":
-        """Replace the kernel-dispatch config (clears the deprecated
-        ``use_kernel`` alias — the explicit config is authoritative)."""
-        return dataclasses.replace(self, kernel=kernel, use_kernel=False)
+        """Replace the kernel-dispatch config."""
+        return dataclasses.replace(self, kernel=kernel)
 
 
 EXACT_CONFIG = WTACRSConfig(kind=EstimatorKind.EXACT, budget=1.0)

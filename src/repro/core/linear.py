@@ -11,16 +11,16 @@ kept as residuals for the backward pass, instead of the full H.  This is
 where the activation-memory reduction comes from: for budget k = 0.3 n the
 per-linear stored activation shrinks 3.3x.
 
-Distribution design (DESIGN.md §Hardware-adaptation): sampling is
-PER-SAMPLE — each batch element draws its own k = budget*S column-row
-pairs over its S token rows.  The contraction sum decomposes over batch
-elements, each estimated unbiasedly, so the total stays unbiased; and
-because every op is elementwise in the batch dimension, data-parallel
-sharding keeps the whole plan+gather shard-local (a global top-|C| over
-the B*S dim would force an all-gather of the activations on every
-linear — measured 1.7 TB/device in the 16x16 dry-run).  The paper's own
-cache granularity is also per-sample (Algorithm 1), so this is the
-faithful SPMD expression of it.
+Distribution design: sampling is PER-SAMPLE — each batch element draws
+its own k = budget*S column-row pairs over its S token rows.  The
+contraction sum decomposes over batch elements, each estimated
+unbiasedly, so the total stays unbiased; and because every op is
+elementwise in the batch dimension, data-parallel sharding keeps the
+whole plan+gather shard-local (a global top-|C| over the B*S dim would
+force an all-gather of the activations on every linear — measured 1.7
+TB/device in the 16x16 dry-run).  The paper's own cache granularity is
+also per-sample (Algorithm 1), so this is the faithful SPMD expression
+of it.
 
 The column-row distribution (Eq. 3) is p_i ∝ ||H_i,:|| * ||dZ_i,:||.  dZ
 is unknown at forward time, so the caller may supply ``znorm`` — cached
@@ -53,7 +53,6 @@ from repro import spans
 from repro.core import estimator_registry as registry
 from repro.core import plans
 from repro.core.config import WTACRSConfig
-from repro.kernels.fused_sampling import gather_scaled
 
 _EPS = 1e-30
 
@@ -93,15 +92,24 @@ def _rowgather(x: jax.Array, idx: jax.Array) -> jax.Array:
     return jax.vmap(lambda xb, ib: jnp.take(xb, ib, axis=0))(x, idx)
 
 
+def gather_scaled(dz: jax.Array, idx: jax.Array,
+                  scale: jax.Array) -> jax.Array:
+    """(B, n, d_out)[B, k] * scale -> (B, k, d_out) in dz's dtype: the
+    scale is applied in f32 and rounded once, so the dW contraction
+    runs on dZ's dtype (bf16 at the MXU's native rate)."""
+    rows = jax.vmap(lambda d, i: jnp.take(d, i, axis=0))(dz, idx)
+    return (rows.astype(jnp.float32)
+            * scale.astype(jnp.float32)[:, :, None]).astype(dz.dtype)
+
+
 def _sampled_dw(h_sub, dz, idx, scale, out_dtype):
     """dW = sum_b H'_b^T @ (dZ_b[idx_b] * scale_b): the k rows of dZ
-    gathered and scaled (``gather_scaled``, in dZ's dtype), then one
-    dot_general contracting (B, k) with f32 accumulation, under the
-    ``wtacrs_dw`` scope.  ``cfg.kernel`` does not route it: on a TPU
-    v5e XLA's dot runs this contraction an order of magnitude faster
-    than the Pallas kernel (``kernels.ops.fused_sampled_dw``, a
-    standalone op), and under a mesh the partitioner shards it over the
-    data axes and adds the cross-shard sum itself."""
+    gathered and scaled (``gather_scaled``), then one dot_general
+    contracting (B, k) with f32 accumulation, under the ``wtacrs_dw``
+    scope.  This is the sampled dW on every ``KernelConfig`` backend
+    (the config routes only the plan's row norms); under a mesh the
+    partitioner shards it over the data axes and adds the cross-shard
+    sum itself."""
     with jax.named_scope(spans.WTACRS_DW):
         dz_sub = gather_scaled(dz, idx, scale)                 # (B, k, E)
         dw = jax.lax.dot_general(

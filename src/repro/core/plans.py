@@ -196,9 +196,9 @@ def build_batched_plans(p: jax.Array, k: int, key_data, cfg) -> SamplePlan:
 
     ``key_data`` is raw PRNG key data (``jax.random.key_data``) so the
     caller can thread it through a custom_vjp; it is split into one key
-    per sample for estimators that need randomness.  This is the plan
-    layout the batched Pallas backward kernel consumes directly (its
-    scalar-prefetched (B, k) index/scale operands).
+    per sample for estimators that need randomness.  The (B, k) idx and
+    scale are what the forward's row gather of H' and the backward's
+    sampled dW (``core.linear._sampled_dw``) index with.
     """
     b = p.shape[0]
     spec = registry.get_estimator(cfg.kind)

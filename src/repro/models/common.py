@@ -230,10 +230,8 @@ class Policy:
         """Apply one :class:`~repro.core.kernel_config.KernelConfig` to
         every estimator config this policy can resolve to: the default
         ``wtacrs``, the rules' ``default``, and each rule's explicit
-        config.  Per-rule ``kernel=``/``use_kernel=`` overrides are
-        left alone — an explicit rule-level choice stays authoritative.
-        This is how ``RunSpec.kernel`` threads one kernel-dispatch
-        decision through the whole policy."""
+        config.  This is how ``RunSpec.kernel`` threads one
+        kernel-dispatch decision through the whole policy."""
         wtacrs = self.wtacrs.with_kernel(kernel)
         rules = self.rules
         if rules is not None:

@@ -160,10 +160,10 @@ def test_lora_zero_b_init_is_identity(setup):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("batch", [1, 2, 8])
 def test_use_kernel_matches_jnp_path(batch, dtype):
-    """The Pallas backend (fused batched backward, interpret mode on
-    CPU) must match the jnp gather + dot_general path for all batch
-    sizes and dtypes — the dW both compute is bitwise the same
-    contraction, only the data movement differs."""
+    """The ``pallas`` and ``jnp`` backends differ only in the plan's
+    row-norm pass (the Pallas kernel, interpret mode on CPU, or an
+    einsum); both then run the same gather and dot_general dW, so their
+    weight gradients agree for all batch sizes and dtypes."""
     key = jax.random.PRNGKey(17)
     h = jax.random.normal(key, (batch, 48, 40)).astype(dtype)
     w = (jax.random.normal(jax.random.fold_in(key, 1), (40, 24))
@@ -185,8 +185,9 @@ def test_use_kernel_matches_jnp_path(batch, dtype):
 
 @pytest.mark.kernel
 def test_use_kernel_dh_and_tap_unaffected():
-    """The kernel only replaces the dW GEMM: dH and the gradient-norm
-    tap must be identical with and without it."""
+    """The backend routes only the row norms that pick the plan, and
+    neither dH nor the gradient-norm tap reads the plan: both are
+    bitwise the same under ``pallas`` and ``jnp``."""
     key = jax.random.PRNGKey(29)
     h = jax.random.normal(key, (3, 32, 24))
     w = jax.random.normal(jax.random.fold_in(key, 1), (24, 16)) * 0.1

@@ -1,5 +1,5 @@
 """Compile-only checks of the Pallas kernels for a TPU v5e: the training
-step's row-norm kernel and the standalone sampled-dW op.
+step's row-norm kernel at the shapes the benchmark's cells feed it.
 
 The TPU compiler is installed with JAX and compiles for a described
 (not attached) ``v5e:2x2`` topology, so these tests catch what
@@ -57,26 +57,24 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (B, k, d_in, d_out) at published widths, S = 512 tokens per sample;
-# k = 154 is budget 0.3 of 512 (ragged: no block divides it), k = 77 is
-# budget 0.15.
-_XLSTM = [(4, 154, 768, 1536), (4, 154, 1536, 768)]
-_QWEN = [(8, k, d_in, d_out) for k in (154, 77)
-         for d_in, d_out in ((2048, 11008), (11008, 2048), (2048, 256))]
+# (B·S, d) of every row-norm call in the benchmark's cells, read from
+# their compiled steps (1024 tokens a row): xlstm-125m at batch 8, and
+# at 16, which compiles too; MiniCPM-2B at 16; the Kanana-2 share at 4.
+# The last pair has rows no block divides and a width with no
+# 128-divisor.
+_ROW_NORM_SHAPES = [(8192, 768), (8192, 1536),
+                    (16384, 768), (16384, 1536),
+                    (16384, 2304), (16384, 5760),
+                    (4096, 512), (4096, 1536), (4096, 2048),
+                    (4096, 4096), (4096, 6144),
+                    (4000, 1000)]
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("b,k,d_in,d_out", _XLSTM + _QWEN)
-def test_fused_sampled_dw_compiles(one_chip, b, k, d_in, d_out, dtype):
-    n = 512
-    hsub = jax.ShapeDtypeStruct((b, k, d_in), dtype, sharding=one_chip)
-    dz = jax.ShapeDtypeStruct((b, n, d_out), dtype, sharding=one_chip)
-    idx = jax.ShapeDtypeStruct((b, k), jnp.int32, sharding=one_chip)
-    scale = jax.ShapeDtypeStruct((b, k), jnp.float32, sharding=one_chip)
-    text = _compiled_text(
-        lambda h, d, i, s: ops.fused_sampled_dw(h, d, i, s,
-                                                kernel=TPU_KERNELS),
-        hsub, dz, idx, scale)
+@pytest.mark.parametrize("n,d", _ROW_NORM_SHAPES)
+def test_row_norms_compiles_at_cell_widths(one_chip, n, d, dtype):
+    x = jax.ShapeDtypeStruct((n, d), dtype, sharding=one_chip)
+    text = _compiled_text(lambda a: ops.row_norms(a, kernel=TPU_KERNELS), x)
     assert "tpu_custom_call" in text
 
 
